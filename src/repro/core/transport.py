@@ -552,8 +552,6 @@ class TransportServer:
             self._eviction_task = None
         if self._server is not None:
             self._server.close()
-            await self._server.wait_closed()
-            self._server = None
         for conn in list(self._conns):
             conn.close()
         tasks = list(self._handler_tasks)
@@ -563,6 +561,11 @@ class TransportServer:
             await asyncio.gather(*tasks, return_exceptions=True)
         self._conns.clear()
         self._handler_tasks.clear()
+        if self._server is not None:
+            # since Python 3.12.1 this also waits for every accepted
+            # connection to close, so it comes after closing them
+            await self._server.wait_closed()
+            self._server = None
         if self.tracer is not None:
             # leases granted but never submitted back (client died, lease
             # watchdog-released): close their wire spans so a stopped

@@ -33,24 +33,18 @@ def _mamba_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref, y_ref, hT_ref,
         state_ref[...] = h0_ref[...][0].astype(jnp.float32)
 
     a = a_ref[...].astype(jnp.float32)                    # (BC, ds)
-    one = pl.dslice(0, 1)  # python-int indices break 0.4.x interpret mode
 
     def step(t, _):
-        tt = pl.dslice(t, 1)
-        dt_t = pl.load(dt_ref, (one, tt,
-                                slice(None)))[0, 0].astype(jnp.float32)
-        x_t = pl.load(x_ref, (one, tt,
-                              slice(None)))[0, 0].astype(jnp.float32)
-        b_t = pl.load(b_ref, (one, tt,
-                              slice(None)))[0, 0].astype(jnp.float32)
-        c_t = pl.load(c_ref, (one, tt,
-                              slice(None)))[0, 0].astype(jnp.float32)
+        tt = pl.ds(t, 1)
+        dt_t = dt_ref[0, tt, :][0].astype(jnp.float32)
+        x_t = x_ref[0, tt, :][0].astype(jnp.float32)
+        b_t = b_ref[0, tt, :][0].astype(jnp.float32)
+        c_t = c_ref[0, tt, :][0].astype(jnp.float32)
         h = state_ref[...]                                # (BC, ds)
         da = jnp.exp(dt_t[:, None] * a)
         h = da * h + (dt_t * x_t)[:, None] * b_t[None, :]
         y = jnp.einsum("cs,s->c", h, c_t)
-        pl.store(y_ref, (one, tt, slice(None)),
-                 y[None, None].astype(y_ref.dtype))
+        y_ref[0, tt, :] = y[None].astype(y_ref.dtype)
         state_ref[...] = h
         return 0
 

@@ -33,24 +33,18 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, y_ref, sT_ref,
         state_ref[...] = s0_ref[...][0, 0].astype(jnp.float32)
 
     u = u_ref[...][0].astype(jnp.float32)                # (hd,)
-    one = pl.dslice(0, 1)  # python-int indices break 0.4.x interpret mode
 
     def step(t, _):
-        tt = pl.dslice(t, 1)
-        r_t = pl.load(r_ref, (one, tt, one,
-                              slice(None)))[0, 0, 0].astype(jnp.float32)
-        k_t = pl.load(k_ref, (one, tt, one,
-                              slice(None)))[0, 0, 0].astype(jnp.float32)
-        v_t = pl.load(v_ref, (one, tt, one,
-                              slice(None)))[0, 0, 0].astype(jnp.float32)
-        w_t = pl.load(w_ref, (one, tt, one,
-                              slice(None)))[0, 0, 0].astype(jnp.float32)
+        tt = pl.ds(t, 1)
+        r_t = r_ref[0, tt, 0, :][0].astype(jnp.float32)
+        k_t = k_ref[0, tt, 0, :][0].astype(jnp.float32)
+        v_t = v_ref[0, tt, 0, :][0].astype(jnp.float32)
+        w_t = w_ref[0, tt, 0, :][0].astype(jnp.float32)
         s = state_ref[...]                               # (hd_k, hd_v)
         kv = k_t[:, None] * v_t[None, :]
         att = s + (u * k_t)[:, None] * v_t[None, :]
         y = jnp.einsum("k,kv->v", r_t, att)
-        pl.store(y_ref, (one, tt, one, slice(None)),
-                 y[None, None, None].astype(y_ref.dtype))
+        y_ref[0, tt, 0, :] = y[None].astype(y_ref.dtype)
         state_ref[...] = w_t[:, None] * s + kv
         return 0
 

@@ -32,6 +32,26 @@ from jax.experimental.pallas import tpu as pltpu
 BLOCK_ROWS = 8
 BLOCK_COLS = 1024
 
+# A grid step holds one f32 block of p, acc, p' and acc' plus one block of
+# every member's gradient, each double-buffered, so the kernel's VMEM
+# grows by two blocks (64 KiB) per member.  Mosaic's default scoped limit
+# (16 MiB) stops at about 250 members; the limit is raised from these
+# bytes up to a budget kept under the 128 MiB of VMEM of a v5e core.
+_BLOCK_BYTES = BLOCK_ROWS * BLOCK_COLS * 4
+_DEFAULT_SCOPED_VMEM = 16 * 2**20
+_VMEM_HEADROOM = 2 * 2**20
+VMEM_BUDGET = 96 * 2**20
+
+
+def vmem_bytes(members: int) -> int:
+    """Scoped VMEM one kernel call asks for with ``members`` gradients."""
+    return 2 * (members + 4) * _BLOCK_BYTES + _VMEM_HEADROOM
+
+
+def max_members() -> int:
+    """The largest cohort one kernel call takes within ``VMEM_BUDGET``."""
+    return (VMEM_BUDGET - _VMEM_HEADROOM) // (2 * _BLOCK_BYTES) - 4
+
 
 def _server_step_kernel(c_ref, p_ref, g_ref, a_ref, po_ref, ao_ref, *,
                         lr: float, beta: float, weight_decay: float,
@@ -74,6 +94,12 @@ def server_step_blocks(p2, g3, acc2, coeffs, *, lr: float, beta: float = 1.0,
     weight per member).  Returns (p2', acc2') f32.
     """
     m, rows = g3.shape[0], p2.shape[0]
+    if vmem_bytes(m) > VMEM_BUDGET:
+        raise ValueError(
+            f"server-step kernel: {m} member gradients need "
+            f"{vmem_bytes(m) / 2**20:.2f} MiB of VMEM, over the "
+            f"{VMEM_BUDGET // 2**20} MiB budget; at most {max_members()} "
+            f"members fit in one call")
     grid = (rows // BLOCK_ROWS,)
     spec2 = pl.BlockSpec((BLOCK_ROWS, BLOCK_COLS), lambda i: (i, 0))
     spec3 = pl.BlockSpec((m, BLOCK_ROWS, BLOCK_COLS), lambda i: (0, i, 0))
@@ -88,6 +114,8 @@ def server_step_blocks(p2, g3, acc2, coeffs, *, lr: float, beta: float = 1.0,
             jax.ShapeDtypeStruct(p2.shape, jnp.float32),
             jax.ShapeDtypeStruct(p2.shape, jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=max(vmem_bytes(m), _DEFAULT_SCOPED_VMEM)),
         interpret=interpret,
     )(coeffs, p2, g3, acc2)
 

@@ -37,14 +37,6 @@ from repro.sharding.spec import to_pspec
 
 MODES = ("pallas", "interpret", "xla")
 
-try:                                  # jax >= 0.6
-    from jax import shard_map as _shard_map
-    _REPL_KW = {"check_vma": False}
-except ImportError:                   # jax 0.4.x spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _REPL_KW = {"check_rep": False}
-
-
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
@@ -97,10 +89,10 @@ def _jit_impl(lr: float, beta: float, weight_decay: float, mode: str,
         elif mesh is not None and ndev > 1:
             body = functools.partial(server_step_blocks,
                                      interpret=(mode == "interpret"), **kw)
-            po, ao = _shard_map(
+            po, ao = jax.shard_map(
                 lambda pp, gg, aa, cc: body(pp, gg, aa, cc),
                 mesh=mesh, in_specs=(ps2, ps3, ps2, psc),
-                out_specs=(ps2, ps2), **_REPL_KW)(p2, g3, acc2, coeffs_f)
+                out_specs=(ps2, ps2), check_vma=False)(p2, g3, acc2, coeffs_f)
         else:
             po, ao = server_step_blocks(p2, g3, acc2, coeffs_f,
                                         interpret=(mode == "interpret"),

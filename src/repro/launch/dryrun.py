@@ -27,8 +27,7 @@ import jax
 
 from repro.configs.base import (ARCH_IDS, INPUT_SHAPES, RunConfig,
                                 get_arch_config)
-from repro.launch.hlo_analysis import (Roofline, cost_analysis_dict,
-                                       parse_collectives,
+from repro.launch.hlo_analysis import (Roofline, parse_collectives,
                                        roofline_from_compiled)
 from repro.launch.mesh import make_production_mesh
 from repro.launch.steps import build_step
@@ -75,7 +74,7 @@ def accounting_costs(cfg, run, shape, mesh) -> dict:
         bundle = build_step(_reduced_depth(cfg, d), run, shape, mesh)
         with flags.unrolled_for_accounting():
             compiled = bundle.lower().compile()
-        cost = cost_analysis_dict(compiled)
+        cost = compiled.cost_analysis()
         coll = parse_collectives(compiled.as_text())
         samples.append({
             "flops": float(cost.get("flops", 0.0)),

@@ -123,19 +123,10 @@ class Roofline:
         }
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``Compiled.cost_analysis()`` returns a dict on recent jax but a
-    one-element list of dicts on 0.4.x; normalise to a dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def roofline_from_compiled(compiled, chips: int, *,
                            model_flops: float = 0.0) -> Roofline:
     """Build a :class:`Roofline` from a jax ``Compiled`` object."""
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
     # XLA reports per-partition numbers for SPMD modules; scale to the fleet.
     flops = float(cost.get("flops", 0.0)) * chips
     byts = float(cost.get("bytes accessed", 0.0)) * chips

@@ -7,11 +7,8 @@ import jax
 
 
 def _mk(shape, axes):
-    # jax.sharding.AxisType landed after 0.4.x; older jax defaults to Auto.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = ({"axis_types": (axis_type.Auto,) * len(axes)}
-              if axis_type is not None else {})
-    return jax.make_mesh(shape, axes, **kwargs)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

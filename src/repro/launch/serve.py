@@ -14,6 +14,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import get_arch_config, get_smoke_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.model import build_model
 
 
@@ -52,6 +53,7 @@ def main():
     ap.add_argument("--gen", type=int, default=32)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_arch_config(args.arch))
     api = build_model(cfg, compute_dtype=jnp.float32, remat=False)

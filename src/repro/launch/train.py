@@ -22,6 +22,7 @@ from repro.configs.base import INPUT_SHAPES, InputShape, RunConfig, \
 from repro.core.split_parallel import init_prev_features, make_train_step
 from repro.data import TicketDataLoader, make_lm_batch
 from repro.data.synthetic import InlineWorker
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh
 from repro.launch.steps import arch_for_run, make_rules
 from repro.models.model import build_model
@@ -105,6 +106,7 @@ def main():
     ap.add_argument("--checkpoint", default=None)
     args = ap.parse_args()
 
+    use_compile_cache()
     cfg = (get_smoke_config(args.arch) if args.smoke
            else get_arch_config(args.arch))
     run = RunConfig(arch=args.arch, strategy=args.strategy,

@@ -279,17 +279,11 @@ def _apply_moe_sharded(p, cfg, x, ctx, *, capacity_factor=None):
         aux = jax.lax.pmean(aux, tuple(mesh.axis_names))
         return y, aux
 
-    try:                                  # jax >= 0.6
-        from jax import shard_map
-        replication_kw = {"check_vma": False}
-    except ImportError:                   # jax 0.4.x spelling
-        from jax.experimental.shard_map import shard_map
-        replication_kw = {"check_rep": False}
     p_vals = {k2: p[k2] for k2 in specs}
-    f = shard_map(
+    f = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, {k2: specs[k2] for k2 in p_vals}),
         out_specs=(x_spec, jax.sharding.PartitionSpec()),
-        **replication_kw)
+        check_vma=False)
     y, aux = f(x, p_vals)
     return y, aux

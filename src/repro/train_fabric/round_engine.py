@@ -42,6 +42,7 @@ import time
 from dataclasses import dataclass, field, replace
 from typing import Any, Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.core.split_parallel import (RoundDriverLifetime, TrainState,
@@ -539,6 +540,9 @@ class FederatedTrainingLoop:
             [g[self.grad_key] for g in got], works,
             self.state.params, self.state.opt_state)
         if self._m_step_s is not None:
+            # time the step, not its dispatch; the next publish reads the
+            # params back to the host anyway, so the round waits no longer
+            jax.block_until_ready(new_params)
             self._m_step_s.observe(time.perf_counter() - t_step)
         self.state = replace(
             self.state, params=new_params, opt_state=new_opt,

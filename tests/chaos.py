@@ -23,6 +23,8 @@ workloads, and generous wall deadlines.
 """
 import asyncio
 
+from hypothesis import given, settings, strategies as st
+
 from repro.core.distributor import (AdaptiveSizer, AsyncDistributor,
                                     ClientProfile, FixedSizer, TaskDef)
 from repro.core.federation import FederatedDistributor
@@ -33,11 +35,6 @@ from repro.core.transport import (PROTOCOL_VERSION, RemoteBrowserClient,
                                   reconnect_backoff, spawn_remote_clients)
 from repro.obs.trace import Tracer
 from repro.train_fabric.round_engine import FederatedTrainer
-
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                        # conftest registers the shim
-    from tests._hypothesis_shim import given, settings, strategies as st
 
 
 # module-level so they pickle across the wire

@@ -1,6 +1,5 @@
 import os
 import sys
-import types
 
 import pytest
 
@@ -10,31 +9,6 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # tests/test_chaos.py) rather than collected directly; opt it into
 # pytest's assert rewriting so its failures stay introspectable
 pytest.register_assert_rewrite("chaos")
-
-# The container has no `hypothesis`; register the deterministic shim in its
-# place so the property tests still execute (see tests/_hypothesis_shim.py).
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    sys.path.insert(0, os.path.dirname(__file__))
-    import _hypothesis_shim as _shim
-
-    hyp = types.ModuleType("hypothesis")
-    hyp.given = _shim.given
-    hyp.settings = _shim.settings
-    hyp.strategies = types.ModuleType("hypothesis.strategies")
-    for _name in ("integers", "floats", "lists", "sampled_from", "booleans",
-                  "just", "binary", "one_of", "tuples"):
-        setattr(hyp.strategies, _name, getattr(_shim, _name))
-    extra = types.ModuleType("hypothesis.extra")
-    extra.numpy = types.ModuleType("hypothesis.extra.numpy")
-    extra.numpy.arrays = _shim.arrays
-    extra.numpy.array_shapes = _shim.array_shapes
-    hyp.extra = extra
-    sys.modules["hypothesis"] = hyp
-    sys.modules["hypothesis.strategies"] = hyp.strategies
-    sys.modules["hypothesis.extra"] = extra
-    sys.modules["hypothesis.extra.numpy"] = extra.numpy
 
 import jax
 

@@ -34,8 +34,7 @@ def test_mini_mesh_lower_compile(arch, shape, strategy):
         from repro.configs.base import INPUT_SHAPES, RunConfig, get_smoke_config
         from repro.launch.mesh import make_local_mesh
         from repro.launch.steps import build_step
-        from repro.launch.hlo_analysis import (cost_analysis_dict,
-                                               roofline_from_compiled)
+        from repro.launch.hlo_analysis import roofline_from_compiled
 
         shape = dataclasses.replace(INPUT_SHAPES["{shape}"], seq_len=256,
                                     global_batch=8)
@@ -50,7 +49,7 @@ def test_mini_mesh_lower_compile(arch, shape, strategy):
         assert roof.flops > 0
         assert mem.temp_size_in_bytes >= 0
         print("MINI_DRYRUN_OK", roof.dominant,
-              cost_analysis_dict(compiled).get("flops", 0))
+              compiled.cost_analysis().get("flops", 0))
     """)
     out = _run(code)
     assert "MINI_DRYRUN_OK" in out

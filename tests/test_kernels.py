@@ -148,6 +148,20 @@ def test_server_step_kernel_sweep(shape, dtype, members, wd):
                                atol=1e-5, rtol=1e-5)
 
 
+def test_server_step_kernel_refuses_a_cohort_over_its_vmem_budget():
+    """Every member's gradient block sits in VMEM at once, so one call
+    takes at most ``max_members()`` gradients; one more is refused with
+    that number before anything is lowered."""
+    from repro.kernels.server_step.kernel import (max_members,
+                                                  server_step_blocks)
+    m = max_members() + 1
+    p2 = jax.ShapeDtypeStruct((8, 1024), jnp.float32)
+    g3 = jax.ShapeDtypeStruct((m, 8, 1024), jnp.float32)
+    coeffs = jax.ShapeDtypeStruct((m,), jnp.float32)
+    with pytest.raises(ValueError, match=f"at most {m - 1} members"):
+        server_step_blocks(p2, g3, p2, coeffs, lr=0.05, interpret=False)
+
+
 def test_flash_attention_matches_model_attention_layer():
     """The kernel agrees with the XLA attention path used by the models."""
     import dataclasses

@@ -9,9 +9,6 @@ Two invariants carry the whole binary protocol:
     the registry's changed-leaves delta to its cached full payload ends
     up bit-for-bit identical to a client that downloaded the full
     payload.  Deltas are an optimisation, never an approximation.
-
-Runs under real `hypothesis` (CI) or the deterministic shim
-(tests/_hypothesis_shim.py) — only the shared API subset is used.
 """
 import dataclasses
 
