@@ -1,5 +1,5 @@
-"""Benchmark harness: one entry per paper table/figure + the roofline table
-and the two virtual-clock scheduler benchmarks.
+"""Benchmark harness: one entry per paper table/figure and the two
+virtual-clock scheduler benchmarks.
 
 Prints ``name,us_per_call,derived`` CSV rows (plus the detailed records) so
 results are machine-comparable across runs.  Scaled-down sizes run inside a
@@ -138,21 +138,6 @@ def bench_fig5(full: bool):
         print(f"  {r}")
     conv = [r["conv_batches_per_min"] for r in rows]
     _csv("fig5_split_scaling", us, f"conv_bpm={conv}")
-    return rows
-
-
-def bench_roofline(full: bool):
-    from benchmarks import roofline
-
-    t0 = time.perf_counter()
-    rows = roofline.run()
-    us = (time.perf_counter() - t0) * 1e6
-    ok = [r for r in rows if "error" not in r]
-    for r in ok[:5]:
-        print(f"  {r}")
-    if len(ok) > 5:
-        print(f"  ... ({len(ok)} rows total; see EXPERIMENTS.md §Roofline)")
-    _csv("roofline_table", us, f"rows={len(ok)}")
     return rows
 
 
@@ -407,7 +392,6 @@ BENCHES = {
     "table4": bench_table4,
     "fig3": bench_fig3,
     "fig5": bench_fig5,
-    "roofline": bench_roofline,
     "scheduler": bench_scheduler,
     "federation": bench_federation,
     "cache": bench_cache,

@@ -220,7 +220,8 @@ class ShardedTicketQueue:
                 self._lease_spans[lease_id] = self.tracer.begin(
                     "lease", track="queue", cat="lease", ts=now,
                     args={"lease": lease_id, "client": client,
-                          "tickets": len(copies), "shards": len(touched)})
+                          "tickets": len(copies), "shards": len(touched),
+                          "ticket_ids": [t.ticket_id for t in copies]})
         with self._stats_lock:
             self.stats.setdefault(client, ClientStats(client)).leases += 1
         return batch

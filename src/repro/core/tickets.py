@@ -455,7 +455,8 @@ class TicketQueue:
                 self._lease_spans[lease_id] = self.tracer.begin(
                     "lease", track="queue", cat="lease", ts=now,
                     args={"lease": lease_id, "client": client,
-                          "tickets": len(picked)})
+                          "tickets": len(picked),
+                          "ticket_ids": [t.ticket_id for t in picked]})
         return batch
 
     def lease_tickets(self, client: str, ticket_ids, *, lease_id: int,

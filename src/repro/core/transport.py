@@ -83,6 +83,8 @@ from repro.core.distributor import (BrowserNodeBase, ClientProfile, Fetched,
                                     TaskDef, merge_unconditional_fetch,
                                     merge_versioned_fetch)
 from repro.core.tickets import LeaseBatch
+from repro.obs.trace import span_on
+from repro.obs.trace import use as use_tracer
 # ProtocolError lives in the leaf module repro.core.wire (the registry's
 # codecs raise it too); re-exported here where it historically lived.
 from repro.core.wire import (ProtocolError, decode_binary, encode_binary,
@@ -845,8 +847,11 @@ class TransportServer:
                 if msg.get("encoding") == "bin":
                     # v2: one binary blob for the whole result dict —
                     # gradient arrays go up raw, no pickle+base64
-                    decoded = decode_binary(msg.get("manifest"),
-                                            msg.get("_blob", b""))
+                    blob = msg.get("_blob", b"")
+                    with span_on(self.tracer, "wire.decode", cat="wire",
+                                 args={"side": "server", "kind": "submit",
+                                       "bytes": len(blob)}):
+                        decoded = decode_binary(msg.get("manifest"), blob)
                     if not isinstance(decoded, dict):
                         raise ProtocolError(
                             "bad-manifest",
@@ -891,8 +896,13 @@ class TransportServer:
                     delta=want_delta)
                 if conn.proto >= 2 and not got.not_modified:
                     # v2: full payloads AND deltas go binary + chunked
-                    header, buffer = _fetch_reply_bin("static_data", seq,
-                                                      got)
+                    with span_on(self.tracer, "wire.encode", cat="wire",
+                                 args={"side": "server",
+                                       "kind": "static_data"}) as span_args:
+                        header, buffer = _fetch_reply_bin("static_data",
+                                                          seq, got)
+                        if span_args is not None:
+                            span_args["bytes"] = len(buffer)
                     await conn.send_blob(header, buffer)
                 else:
                     await conn.send(_fetch_reply("static_data", seq, got))
@@ -1301,11 +1311,21 @@ class RemoteBrowserClient(BrowserNodeBase):
         self.cache.put(cache_key, new)
         return new.value
 
+    def _decode_reply(self, reply: dict) -> Fetched:
+        """:func:`_decode_fetch`, inside a ``wire.decode`` span where the
+        reply is binary and this client traces."""
+        if self.tracer is None or reply.get("encoding") != "bin":
+            return _decode_fetch(reply)
+        with self.tracer.span("wire.decode", cat="wire",
+                              args={"side": "client", "kind": reply["type"],
+                                    "bytes": len(reply.get("_blob", b""))}):
+            return _decode_fetch(reply)
+
     async def _get_task(self, name: str, min_version: int = 0) -> TaskDef:
         """Task code through the cache; a pin newer than the cached entry
         forces a conditional ``fetch_task`` round-trip."""
         async def fetch(v):
-            return _decode_fetch(await self._request(
+            return self._decode_reply(await self._request(
                 {"type": "fetch_task", "name": name, "if_version": v}))
         return await self._aget_versioned(f"task:{name}", fetch, min_version)
 
@@ -1321,7 +1341,7 @@ class RemoteBrowserClient(BrowserNodeBase):
                 req = {"type": "fetch_static", "key": k, "if_version": v}
                 if v is not None and self.proto >= 2:
                     req["delta"] = True
-                return _decode_fetch(await self._request(req))
+                return self._decode_reply(await self._request(req))
             out[key] = await self._aget_versioned(f"static:{key}", fetch,
                                                   min_version)
         return out
@@ -1406,7 +1426,12 @@ class RemoteBrowserClient(BrowserNodeBase):
         if echo is not None:
             extra["trace"] = echo
         if self.proto >= 2:
-            manifest, buffer = encode_binary(results)
+            with span_on(self.tracer, "wire.encode", cat="wire",
+                         args={"side": "client",
+                               "kind": "submit"}) as span_args:
+                manifest, buffer = encode_binary(results)
+                if span_args is not None:
+                    span_args["bytes"] = len(buffer)
             reply = await self._request(
                 {"type": "submit", "lease_id": lease_id,
                  "encoding": "bin", "manifest": manifest, **extra},
@@ -1537,8 +1562,11 @@ class RemoteBrowserClient(BrowserNodeBase):
                         await self._paced_sleep(
                             ticket.work / self.profile.speed,
                             batch.lease_id)
-                    results[str(ticket.ticket_id)] = task.run(ticket.args,
-                                                              static)
+                    # the task is handed no tracer: it finds ours as
+                    # current (the block holds no await)
+                    with use_tracer(tr):
+                        results[str(ticket.ticket_id)] = task.run(
+                            ticket.args, static)
                     self.executed += 1
                 except (ConnectionError, asyncio.IncompleteReadError,
                         OSError, ProtocolError):

@@ -117,6 +117,7 @@ def server_step_blocks(p2, g3, acc2, coeffs, *, lr: float, beta: float = 1.0,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=max(vmem_bytes(m), _DEFAULT_SCOPED_VMEM)),
         interpret=interpret,
+        name="server_step_update",
     )(coeffs, p2, g3, acc2)
 
 

@@ -23,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.paper_cnn import CNNConfig
+from repro.obs import trace
 from repro.sharding.spec import Param, param, shard_act
 
 
@@ -99,15 +100,17 @@ def error_rate(logits, labels):
 def loss_and_grads(ccfg: CNNConfig):
     """Jitted ``(params, images, labels) -> (mean NLL, grad pytree)`` for
     plain (unboxed) params, cached per config so every shard of a round
-    — and every round — reuses one compiled executable."""
+    — and every round — reuses one compiled executable.  The program is
+    named ``cnn_loss_and_grads`` on the profiler's timeline."""
 
     @jax.jit
-    def f(params, images, labels):
+    def cnn_loss_and_grads(params, images, labels):
         def loss_fn(p):
             return nll_loss(forward(p, ccfg, images), labels)
-        return jax.value_and_grad(loss_fn)(params)
+        with jax.named_scope("cnn_loss_and_grads"):
+            return jax.value_and_grad(loss_fn)(params)
 
-    return f
+    return cnn_loss_and_grads
 
 
 @functools.lru_cache(maxsize=None)
@@ -131,6 +134,12 @@ class CnnGradShard:
     ``{"grad", "loss", "round"}`` with gradients device_get'ed to plain
     numpy so the result pickles over the v2 wire protocol.
 
+    Where a tracer is current (``repro.obs.trace.use``), the copy of the
+    params and rows to the device, the program until its gradients are
+    ready, and their copy back run one after another, each in its own
+    span (``grad.h2d``, ``grad.compute``, ``grad.d2h``); without one the
+    call runs as a single dispatch.
+
     A frozen dataclass of hashable config rather than a closure: remote
     clients receive the task by pickle, and the jitted grad function is
     looked up per-process from the :func:`loss_and_grads` cache.
@@ -145,8 +154,35 @@ class CnnGradShard:
         lo, hi = args
         images, labels = shard_dataset(self.ccfg, self.n_rows, self.seed)
         served = static[self.weights_key]
-        loss, grads = loss_and_grads(self.ccfg)(
-            served["params"], jnp.asarray(images[lo:hi]),
-            jnp.asarray(labels[lo:hi]))
-        return {"grad": jax.device_get(grads), "loss": float(loss),
+        tr = trace.current()
+        if tr is None:
+            loss, grads = loss_and_grads(self.ccfg)(
+                served["params"], jnp.asarray(images[lo:hi]),
+                jnp.asarray(labels[lo:hi]))
+            grads = jax.device_get(grads)
+        else:
+            loss, grads = _traced_loss_and_grads(
+                tr, self.ccfg, served["params"], images[lo:hi],
+                labels[lo:hi])
+        return {"grad": grads, "loss": float(loss),
                 "round": served.get("round", -1)}
+
+
+def _nbytes(tree) -> int:
+    return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
+
+
+def _traced_loss_and_grads(tr, ccfg, params, images, labels):
+    """:func:`loss_and_grads` with its transfers split from its compute,
+    each timed in a span of ``tr``; gradients returned on the host."""
+    with tr.span("grad.h2d", cat="grad") as args:
+        args["bytes"] = _nbytes((params, images, labels))
+        operands = jax.block_until_ready(
+            jax.device_put((params, images, labels)))
+    with tr.span("grad.compute", cat="grad"):
+        loss, grads = jax.block_until_ready(
+            loss_and_grads(ccfg)(*operands))
+    with tr.span("grad.d2h", cat="grad") as args:
+        grads = jax.device_get(grads)
+        args["bytes"] = _nbytes(grads)
+    return loss, grads

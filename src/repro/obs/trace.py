@@ -9,8 +9,9 @@ Design constraints, in order:
 
   * **Zero-cost when disabled.**  Nothing in the fabric holds a tracer by
     default; every instrumentation site is guarded by a single
-    ``if tracer is not None`` attribute check.  There is no global
-    registry and no no-op call overhead on the hot path.
+    ``if tracer is not None`` attribute check, or one read of the
+    current-tracer ``ContextVar`` (unset unless a traced caller sets
+    it).  There is no global registry.
   * **Deterministic on the virtual clock.**  The tracer never reads wall
     time on its own when a caller supplies ``ts``; when it must, it uses
     its injectable ``clock`` (set it to the queue's clock).  Two
@@ -27,6 +28,14 @@ Span encoding: lifecycle spans that overlap arbitrarily on one lane
 (``ph: "b"/"e"`` pairs keyed by span id); per-lane sequential spans
 (client execute, wire transfer, round barriers) are emitted as complete
 ``ph: "X"`` slices so Perfetto nests them on their track.
+
+Host work is timed by :meth:`Tracer.span`, a context manager around a
+synchronous block (weight publish, wire encode and decode, host<->device
+copies, a device program until its result is ready).  Such a block holds
+no ``await``, so it says what the thread itself was doing; its decoded
+event carries ``"block": True``.  Code that is handed no tracer reaches
+the *current* one, a ``ContextVar`` set with :func:`use`, through
+:func:`span`; with none current, :func:`span` records nothing.
 
 Two long-running-fleet modes sit on top of the default
 record-everything behaviour, both off unless asked for:
@@ -48,9 +57,12 @@ import json
 import threading
 import time
 from collections import deque
+from contextlib import contextmanager, nullcontext
+from contextvars import ContextVar
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-__all__ = ["Tracer", "render_chrome_trace"]
+__all__ = ["Tracer", "current", "render_chrome_trace", "span", "span_on",
+           "use"]
 
 _US = 1e6      # Chrome trace-event timestamps are microseconds
 
@@ -104,7 +116,8 @@ class Tracer:
         # finished events, in completion order (deterministic under the
         # single-threaded virtual-clock sims).  Stored as compact tuples
         # (ph, name, cat, track, ts0, ts1, sid, args) — ph "X" lane
-        # slice, "a" async begin/end pair, "i" instant — and decoded to
+        # slice, "B" lane slice of a synchronous block (see span()), "a"
+        # async begin/end pair, "i" instant — and decoded to
         # the dict schema lazily in events()/chrome_trace(), keeping the
         # record path (the only part on the fabric's hot path) cheap.
         # With max_events set the store is a bounded ring: the oldest
@@ -200,6 +213,26 @@ class Tracer:
             self._append(("X" if rec[3] else "a", rec[0], rec[1],
                           rec[2], rec[4], ts, sid, rec[5], args))
 
+    @contextmanager
+    def span(self, name: str, *, track: str = "host", cat: str = "host",
+             args: Optional[dict] = None):
+        """Record a lane span around a synchronous block.  Yields the
+        span's args dict, to which the block may add what it counted
+        (``bytes``, ``leaves``) before it ends.  The block must not
+        ``await``: the span says what the thread was doing throughout,
+        and a coroutine suspended inside it would let other work run
+        under its name.  Balanced by construction, so it leaves the
+        open-span counters alone."""
+        args = {} if args is None else args
+        ts0 = self.clock()
+        try:
+            yield args
+        finally:
+            ts1 = self.clock()
+            with self._lock:
+                self._append(("B", name, cat, track, ts0, ts1, 0,
+                              args or None, None))
+
     def instant(self, name: str, *, track: str = "fabric",
                 cat: str = "fabric", ts: Optional[float] = None,
                 args: Optional[dict] = None) -> None:
@@ -271,7 +304,8 @@ class Tracer:
 
     def events(self) -> List[dict]:
         """Finished events decoded to the internal dict schema (seconds
-        timestamps): lane spans as ``ph "X"`` with ``dur``, async spans
+        timestamps): lane spans as ``ph "X"`` with ``dur`` (and ``block:
+        True`` where :meth:`span` recorded them), async spans
         as ``ph "b"/"e"`` pairs sharing an ``id``, instants as ``ph
         "i"``."""
         with self._lock:
@@ -285,9 +319,12 @@ class Tracer:
             if args_end:
                 args = {**args, **args_end} if args else args_end
             base = {"name": name, "cat": cat, "track": track}
-            if ph == "X":
-                out.append({**base, "ph": "X", "ts": ts0,
-                            "dur": max(0.0, ts1 - ts0), "args": args or {}})
+            if ph == "X" or ph == "B":
+                ev = {**base, "ph": "X", "ts": ts0,
+                      "dur": max(0.0, ts1 - ts0), "args": args or {}}
+                if ph == "B":
+                    ev["block"] = True
+                out.append(ev)
             elif ph == "a":
                 out.append({**base, "ph": "b", "id": sid, "ts": ts0,
                             "args": args or {}})
@@ -317,3 +354,42 @@ class Tracer:
     def write(self, path: str) -> None:
         with open(path, "w") as fh:
             fh.write(self.to_json())
+
+
+# -- the current tracer ------------------------------------------------------
+
+_CURRENT: ContextVar[Optional[Tracer]] = ContextVar("repro_tracer",
+                                                    default=None)
+
+
+def current() -> Optional[Tracer]:
+    """The tracer set by the innermost :func:`use`, or None."""
+    return _CURRENT.get()
+
+
+@contextmanager
+def _using(tracer: Tracer):
+    token = _CURRENT.set(tracer)
+    try:
+        yield tracer
+    finally:
+        _CURRENT.reset(token)
+
+
+def use(tracer: Optional[Tracer]):
+    """A context in which ``tracer`` is the current tracer.  With
+    ``tracer=None`` the current tracer stays as it is, so a component
+    built without one can wrap its calls unconditionally."""
+    return nullcontext() if tracer is None else _using(tracer)
+
+
+def span_on(tracer: Optional[Tracer], name: str, **kw):
+    """``tracer.span(name, **kw)``, or, with ``tracer=None``, a context
+    that records nothing and yields None in place of the args dict."""
+    return nullcontext() if tracer is None else tracer.span(name, **kw)
+
+
+def span(name: str, **kw):
+    """:meth:`Tracer.span` on the current tracer; a
+    ``contextlib.nullcontext()`` where none is current."""
+    return span_on(_CURRENT.get(), name, **kw)

@@ -48,6 +48,8 @@ import jax.numpy as jnp
 from repro.core.split_parallel import (RoundDriverLifetime, TrainState,
                                        adaptive_shard_sizes)
 from repro.core.tickets import CANCELLED
+from repro.obs.trace import span_on
+from repro.obs.trace import use as use_tracer
 from repro.train_fabric.checkpointing import (checkpoint_path,
                                               save_round_checkpoint)
 from repro.train_fabric.server_step import (ServerStep, TreeServerStep,
@@ -298,12 +300,20 @@ class FederatedTrainer(RoundDriverLifetime):
         if shard_work is None:
             shard_work = [1.0] * n
         publish_deltas: dict = {}
+        tr = self.tracer
         if statics:
             stats_fn = getattr(self.dist, "static_delta_stats", None)
-            for key, value in statics.items():
-                self.dist.add_static(key, value)
-                if stats_fn is not None:
-                    publish_deltas[key] = stats_fn(key)
+            with span_on(tr, "round.publish", cat="round",
+                         args={"round": self.rounds}) as span_args:
+                for key, value in statics.items():
+                    self.dist.add_static(key, value)
+                    if stats_fn is not None:
+                        publish_deltas[key] = stats_fn(key)
+                if span_args is not None:
+                    span_args["leaves"] = sum(
+                        d["leaves"] for d in publish_deltas.values())
+                    span_args["changed"] = sum(
+                        d["changed"] for d in publish_deltas.values())
         t0 = self.dist.queue.clock()
         groups = self.placement(n)
         if groups is None:
@@ -324,7 +334,6 @@ class FederatedTrainer(RoundDriverLifetime):
         reticketed = 0
         did_reticket = False
         folded: list[int] = []
-        tr = self.tracer
         round_span = None
         span_status = "ok"
         if tr is not None:
@@ -536,9 +545,11 @@ class FederatedTrainingLoop:
                     self._m_stale.inc()
         works = [shard_work[p] for p in res.arrived]
         t_step = time.perf_counter()
-        new_params, new_opt = self.server_step.step(
-            [g[self.grad_key] for g in got], works,
-            self.state.params, self.state.opt_state)
+        # the step is handed no tracer: it finds the trainer's as current
+        with use_tracer(self.trainer.tracer):
+            new_params, new_opt = self.server_step.step(
+                [g[self.grad_key] for g in got], works,
+                self.state.params, self.state.opt_state)
         if self._m_step_s is not None:
             # time the step, not its dispatch; the next publish reads the
             # params back to the host anyway, so the round waits no longer

@@ -38,6 +38,7 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 
+from repro.obs import trace
 from repro.optim import Optimizer
 from repro.optim.adagrad_math import adagrad_leaf_update
 
@@ -71,13 +72,13 @@ def member_grad_norms(grads: Sequence) -> jnp.ndarray:
 @functools.lru_cache(maxsize=None)
 def _coeffs_jit(clip_norm: Optional[float]):
     @jax.jit
-    def f(grads_tuple, works):
+    def member_coeffs(grads_tuple, works):   # names the program
         w = works / jnp.sum(works)
         if clip_norm is not None:
             norms = member_grad_norms(grads_tuple)
             w = w * jnp.minimum(1.0, clip_norm / jnp.maximum(norms, 1e-12))
         return w
-    return f
+    return member_coeffs
 
 
 def member_coeffs(grads: Sequence, works: Sequence[float],
@@ -221,11 +222,31 @@ class FusedServerStep(ServerStep):
 
         # leafwise only without a mesh: the sharded paths (GSPMD / the
         # shard_map'd kernel) need the flat row-partitioned buffer
-        self._jit = jax.jit(leafwise if self.mode == "xla"
-                            and mesh is None else flat)
+        body = leafwise if self.mode == "xla" and mesh is None else flat
+
+        def fused_server_step(grads_tuple, coeffs, params, acc):
+            return body(grads_tuple, coeffs, params, acc)
+
+        self._jit = jax.jit(fused_server_step)
 
     def step(self, grads, works, params, opt_state):
-        coeffs = member_coeffs(grads, works, self.clip_norm)
-        new_params, new_acc = self._jit(tuple(grads), coeffs, params,
-                                        opt_state["acc"])
+        tr = trace.current()
+        if tr is None:
+            coeffs = member_coeffs(grads, works, self.clip_norm)
+            new_params, new_acc = self._jit(tuple(grads), coeffs, params,
+                                            opt_state["acc"])
+            return new_params, {"acc": new_acc}
+        # traced: the coefficients, the copy of the M host gradients to
+        # the device and the fused program, each until ready in its span
+        with tr.span("server_step.coeffs", cat="server_step",
+                     args={"M": len(grads)}):
+            coeffs = jax.block_until_ready(
+                member_coeffs(grads, works, self.clip_norm))
+        with tr.span("server_step.h2d", cat="server_step") as args:
+            args["bytes"] = sum(x.nbytes for x in
+                                jax.tree_util.tree_leaves(grads))
+            dev = jax.block_until_ready(jax.device_put(tuple(grads)))
+        with tr.span("server_step.compute", cat="server_step"):
+            new_params, new_acc = jax.block_until_ready(
+                self._jit(dev, coeffs, params, opt_state["acc"]))
         return new_params, {"acc": new_acc}

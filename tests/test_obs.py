@@ -510,3 +510,267 @@ def test_run_until_done_virtual_timeout_warns_with_timeout_reason():
     ok, report = _run(go())
     assert ok is False and report["reason"] == "timeout"
     assert report["virtual_clock"] > 5.0
+
+
+# ---------------------------------------------------------------------------
+# Spans around synchronous host work, and the current tracer
+# ---------------------------------------------------------------------------
+
+
+def test_tracer_span_nests_and_stays_balanced():
+    clock = SimClock()
+    tr = Tracer(clock=clock)
+    with tr.span("outer", cat="round", args={"round": 3}) as outer:
+        clock.t = 1.0
+        with tr.span("inner", cat="wire") as inner:
+            clock.t = 1.5
+            inner["bytes"] = 42
+        clock.t = 2.0
+        outer["leaves"] = 7
+    with pytest.raises(RuntimeError):
+        with tr.span("raises"):
+            clock.t = 3.0
+            raise RuntimeError("boom")
+    assert tr.balanced()
+    evs = tr.events()
+    # completion order: the inner block ends first
+    assert [e["name"] for e in evs] == ["inner", "outer", "raises"]
+    inner_ev, outer_ev, raised = evs
+    assert all(e["ph"] == "X" and e["block"] is True for e in evs)
+    assert all(e["track"] == "host" for e in evs)
+    assert (inner_ev["ts"], inner_ev["dur"]) == (1.0, 0.5)
+    assert (outer_ev["ts"], outer_ev["dur"]) == (0.0, 2.0)
+    assert outer_ev["ts"] <= inner_ev["ts"] and (
+        inner_ev["ts"] + inner_ev["dur"] <= outer_ev["ts"] + outer_ev["dur"])
+    assert inner_ev["args"] == {"bytes": 42}
+    assert outer_ev["args"] == {"round": 3, "leaves": 7}
+    assert (raised["ts"], raised["dur"]) == (2.0, 1.0)
+    # lane spans of begin/end carry no block mark; Chrome export drops it
+    x = tr.begin("client.execute", lane=True)
+    tr.end(x)
+    assert "block" not in tr.events()[-1]
+    assert all("block" not in e for e in tr.chrome_trace()["traceEvents"])
+
+
+def test_trace_span_is_a_noop_without_a_current_tracer():
+    from contextlib import nullcontext
+
+    from repro.obs import trace
+    assert trace.current() is None
+    ctx = trace.span("grad.h2d")
+    assert isinstance(ctx, nullcontext)
+    with ctx as args:
+        assert args is None
+    with trace.use(None):                       # keeps "none current"
+        assert trace.current() is None
+    tr = Tracer(clock=SimClock())
+    with trace.use(tr):
+        assert trace.current() is tr
+        with trace.use(None):                   # keeps the outer one
+            assert trace.current() is tr
+        with trace.span("grad.h2d", cat="grad") as args:
+            args["bytes"] = 1
+    assert trace.current() is None
+    assert [(e["name"], e["args"]) for e in tr.events()] == [
+        ("grad.h2d", {"bytes": 1})]
+
+
+@pytest.mark.parametrize("sharded", [False, True])
+def test_lease_span_carries_its_ticket_ids(sharded):
+    from repro.core.shards import ShardedTicketQueue
+    clock = SimClock()
+    tr = Tracer(clock=clock)
+    q = (ShardedTicketQueue(3, timeout=30.0, redistribute_min=1.0,
+                            clock=clock, tracer=tr) if sharded
+         else TicketQueue(timeout=30.0, redistribute_min=1.0, clock=clock,
+                          tracer=tr))
+    q.add_many("t", list(range(7)))
+    batches = [q.lease("a", 3), q.lease("b", 4)]
+    for b in batches:
+        q.submit_batch(b.lease_id, {t: t for t in b.ticket_ids}, b.client)
+    assert tr.balanced()
+    leases = {e["args"]["lease"]: e["args"] for e in tr.events()
+              if e["name"] == "lease" and e["ph"] == "b"}
+    assert {lid: a["ticket_ids"] for lid, a in leases.items()} == {
+        b.lease_id: list(b.ticket_ids) for b in batches}
+    assert all(a["tickets"] == len(a["ticket_ids"]) for a in leases.values())
+
+
+# the paper CNN at fabric size, through remote clients over loopback
+
+
+def _fabric_cnn():
+    from repro.configs.paper_cnn import FABRIC_CNN
+    return FABRIC_CNN
+
+
+def _cnn_params(ccfg, seed=0):
+    import jax
+
+    from repro.models.cnn import init_cnn
+    from repro.sharding.spec import values_tree
+    return jax.device_get(values_tree(init_cnn(jax.random.PRNGKey(seed),
+                                               ccfg)))
+
+
+async def _traced_cnn_rounds(m: int, rounds: int, tracer):
+    from repro.core.split_parallel import TrainState
+    from repro.models.cnn import CnnGradShard
+    from repro.optim import adagrad
+    from repro.train_fabric import FederatedTrainingLoop, FusedServerStep
+
+    ccfg = _fabric_cnn()
+    rows = ccfg.batch_size
+    task = CnnGradShard(ccfg, n_rows=m * rows, seed=1)
+    fed = make_fed(2, n_shards=4, redistribute_min=10.0,
+                   sizer=FixedSizer(1), tracer=tracer)
+    fed.register_task(TaskDef("cnn", task, static_files=("weights",)))
+    server = TransportServer(fed)
+    addr = await server.start()
+    clients, tasks = spawn_remote_clients(
+        addr, [ClientProfile(name=f"c{i}", speed=0.0) for i in range(m)],
+        reconnect_delay=0.02, tracer=tracer)
+    opt = adagrad(0.02)
+    params = _cnn_params(ccfg)
+    state = TrainState(params=params, head={}, head_stale={},
+                       opt_state=opt.init(params), head_opt_state={},
+                       prev_features=(), prev_labels=(), prev_mask=(),
+                       step=np.zeros((), np.int32))
+    trainer = FederatedTrainer(fed, task_name="cnn", timeout=60.0)
+    loop = FederatedTrainingLoop(
+        trainer, opt, state,
+        server_step=FusedServerStep(opt, lr=0.02, mode="xla"))
+    args = [(i * rows, (i + 1) * rows) for i in range(m)]
+    results = []
+    try:
+        # every client parked on a lease before the first round, so that
+        # each of them takes one of the round's M tickets
+        for _ in range(3000):
+            frames = server.stats()["by_type"]["frames_in"]
+            if frames.get("lease_request", 0) >= m:
+                break
+            await asyncio.sleep(0.01)
+        async with trainer:
+            for _ in range(rounds):
+                results.append(await loop.run_round(args, [float(rows)] * m))
+    finally:
+        for c in clients:
+            await c.stop()
+        await asyncio.gather(*tasks, return_exceptions=True)
+        await server.stop()
+        await fed.shutdown()
+    return results, clients
+
+
+def test_traced_cnn_round_records_each_host_span_once_per_piece_of_work():
+    import jax
+    m, rounds = 4, 2
+    tr = Tracer()
+    results, clients = _run(_traced_cnn_rounds(m, rounds, tr))
+    assert all(r.complete for r in results)
+    assert tr.balanced(), tr.open_spans()
+    blocks = [e for e in tr.events() if e.get("block")]
+    count = {}
+    for e in blocks:
+        count[e["name"]] = count.get(e["name"], 0) + 1
+    # per round: one publish; each of the M shards has its weights
+    # encoded by the server and decoded by its client, and its gradient
+    # encoded by the client and decoded by the server
+    assert count == {"round.publish": rounds,
+                     "wire.encode": 2 * m * rounds,
+                     "wire.decode": 2 * m * rounds,
+                     "grad.h2d": m * rounds, "grad.compute": m * rounds,
+                     "grad.d2h": m * rounds,
+                     "server_step.coeffs": rounds,
+                     "server_step.h2d": rounds,
+                     "server_step.compute": rounds}
+    by = lambda name, **kv: [e["args"] for e in blocks if e["name"] == name
+                             and all(e["args"].get(k) == v
+                                     for k, v in kv.items())]
+    assert len(by("wire.encode", side="server", kind="static_data")) == \
+        len(by("wire.encode", side="client", kind="submit")) == m * rounds
+    assert len(by("wire.decode", side="client", kind="static_data")) == \
+        len(by("wire.decode", side="server", kind="submit")) == m * rounds
+    # a client decodes what the server encoded, and the other way round
+    assert sorted(a["bytes"] for a in by("wire.encode", side="server")) \
+        == sorted(a["bytes"] for a in by("wire.decode", side="client"))
+    assert sorted(a["bytes"] for a in by("wire.encode", side="client")) \
+        == sorted(a["bytes"] for a in by("wire.decode", side="server"))
+    ccfg = _fabric_cnn()
+    p_bytes = sum(x.nbytes for x in
+                  jax.tree_util.tree_leaves(_cnn_params(ccfg)))
+    rows = ccfg.batch_size
+    x_bytes = rows * ccfg.image_size ** 2 * ccfg.in_channels * 4 + rows * 4
+    assert all(a["bytes"] == p_bytes + x_bytes for a in by("grad.h2d"))
+    assert all(a["bytes"] == p_bytes for a in by("grad.d2h"))
+    assert all(a["bytes"] == m * p_bytes for a in by("server_step.h2d"))
+    assert all(a["M"] == m for a in by("server_step.coeffs"))
+    # the publish is the round tag and every param leaf, all changed
+    n_leaves = len(jax.tree_util.tree_leaves(_cnn_params(ccfg)))
+    assert [(a["leaves"], a["changed"]) for a in by("round.publish")] == [
+        (n_leaves + 1, n_leaves + 1)] * rounds
+    # each leased ticket's id is on its lease span
+    leased = sorted(t for e in tr.events() if e["name"] == "lease"
+                    and e["ph"] == "b" for t in e["args"]["ticket_ids"])
+    assert leased == sorted(t for r in results for t in r.ticket_ids)
+
+
+def _grad_and_step(tracer, mode):
+    import jax
+
+    from repro.models.cnn import CnnGradShard
+    from repro.obs import trace
+    from repro.optim import adagrad
+    from repro.train_fabric import FusedServerStep
+
+    ccfg = _fabric_cnn()
+    rows = ccfg.batch_size
+    params = _cnn_params(ccfg, seed=3)
+    task = CnnGradShard(ccfg, n_rows=3 * rows, seed=5)
+    opt = adagrad(0.02)
+    step = FusedServerStep(opt, lr=0.02, mode=mode)
+    with trace.use(tracer):
+        outs = [task(((i * rows), (i + 1) * rows),
+                     {"weights": {"round": 0, "params": params}})
+                for i in range(3)]
+        new = step.step([o["grad"] for o in outs], [1.0, 2.0, 3.0], params,
+                        opt.init(params))
+    return jax.device_get((outs, new))
+
+
+@pytest.mark.parametrize("mode", ["xla", "interpret"])
+def test_grad_shard_and_fused_step_are_bit_identical_when_traced(mode):
+    import jax
+    tr = Tracer()
+    plain = _grad_and_step(None, mode)
+    traced = _grad_and_step(tr, mode)
+    a, b = jax.tree_util.tree_leaves(plain), jax.tree_util.tree_leaves(traced)
+    assert len(a) == len(b)
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+    names = [e["name"] for e in tr.events()]
+    assert names == ["grad.h2d", "grad.compute", "grad.d2h"] * 3 + [
+        "server_step.coeffs", "server_step.h2d", "server_step.compute"]
+
+
+def test_device_programs_carry_stable_names():
+    """A trace reduction finds each program by its module name,
+    ``jit_<name>``, whatever the code around it is called."""
+    from repro.models.cnn import loss_and_grads
+    from repro.optim import adagrad
+    from repro.train_fabric import FusedServerStep
+    from repro.train_fabric.server_step import _coeffs_jit
+    ccfg = _fabric_cnn()
+    params = _cnn_params(ccfg)
+    x = np.zeros((2, ccfg.image_size, ccfg.image_size, ccfg.in_channels),
+                 np.float32)
+    module = lambda lowered: lowered.as_text().split("\n", 1)[0]
+    assert "@jit_cnn_loss_and_grads" in module(
+        loss_and_grads(ccfg).lower(params, x, np.zeros(2, np.int32)))
+    coeffs = np.ones(2, np.float32)
+    assert "@jit_member_coeffs" in module(
+        _coeffs_jit(None).lower((params, params), coeffs))
+    for mode in ("xla", "interpret"):
+        step = FusedServerStep(adagrad(0.02), lr=0.02, mode=mode)
+        assert "@jit_fused_server_step" in module(
+            step._jit.lower((params, params), coeffs, params, params))

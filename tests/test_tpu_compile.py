@@ -67,7 +67,11 @@ def test_fused_server_step_compiles_at_fig4_widths(one_chip, members):
     coeffs = jax.ShapeDtypeStruct((members,), jnp.float32, sharding=one_chip)
     compiled = step._jit.lower((params,) * members, coeffs, params,
                                params).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the names a trace reduction finds the program and its kernel by
+    assert text.startswith("HloModule jit_fused_server_step,")
+    assert "%server_step_update" in text
 
 
 def test_server_step_kernel_compiles_at_256_members(one_chip):
@@ -89,3 +93,4 @@ def test_cnn_loss_and_grads_compiles_at_fig4_batch(one_chip):
     compiled = cnn.loss_and_grads(FIG4_CNN).lower(
         _fig4_params(one_chip), images, labels).compile()
     assert compiled.memory_analysis() is not None
+    assert compiled.as_text().startswith("HloModule jit_cnn_loss_and_grads,")
