@@ -77,6 +77,7 @@ import random
 import struct
 import time
 import traceback
+from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Optional
 
 from repro.core.distributor import (BrowserNodeBase, ClientProfile, Fetched,
@@ -1040,6 +1041,15 @@ class ServerBusy(ConnectionError):
         self.retry_after = retry_after
 
 
+# The one thread that runs the tasks of every RemoteBrowserClient of the
+# process, one ticket at a time.  The clients of a process share one
+# interpreter and, in a deployment stand-in, one device: more threads
+# would wait on each other inside their tasks and stretch each task's
+# host time, without adding device work.
+_TICKET_WORKER = ThreadPoolExecutor(max_workers=1,
+                                    thread_name_prefix="ticket-worker")
+
+
 def reconnect_backoff(attempt: int, *, base: float, cap: float,
                       rand: Callable[[], float]) -> float:
     """Delay before reconnect ``attempt`` (1-based): capped exponential
@@ -1085,6 +1095,14 @@ class RemoteBrowserClient(BrowserNodeBase):
     slow-but-alive device holding a lease is never mistaken for a closed
     tab (``None`` disables; the mid-lease fetch round-trips also count
     as liveness server-side).
+
+    **Execution**: each ticket's task runs on the process's one ticket
+    worker thread, so that a task waiting on its device leaves the event
+    loop, and the other clients and the server on it, free to run.  A
+    client runs its lease's tickets one at a time, in order, as a
+    browser does, and the clients of a process take turns on the worker:
+    the overlap is of one task with the loop.  The task finds this
+    client's tracer as current there.
     """
 
     def __init__(self, host: str, port: int, profile: ClientProfile, *,
@@ -1511,6 +1529,12 @@ class RemoteBrowserClient(BrowserNodeBase):
         if seconds > 0:
             await asyncio.sleep(seconds)
 
+    def _execute(self, task: TaskDef, args, static: dict):
+        """One ticket's task, on the ticket worker, with this client's
+        tracer as current."""
+        with use_tracer(self.tracer):
+            return task.run(args, static)
+
     async def _one_lease(self) -> bool:
         """One lease round; returns False when the server says the work is
         done (client exits).  Finished-but-unsubmitted results are parked
@@ -1562,11 +1586,12 @@ class RemoteBrowserClient(BrowserNodeBase):
                         await self._paced_sleep(
                             ticket.work / self.profile.speed,
                             batch.lease_id)
-                    # the task is handed no tracer: it finds ours as
-                    # current (the block holds no await)
-                    with use_tracer(tr):
-                        results[str(ticket.ticket_id)] = task.run(
-                            ticket.args, static)
+                    # off the loop; an exception in the task surfaces
+                    # here, in the except clauses below
+                    result = await asyncio.get_running_loop(
+                    ).run_in_executor(_TICKET_WORKER, self._execute, task,
+                                      ticket.args, static)
+                    results[str(ticket.ticket_id)] = result
                     self.executed += 1
                 except (ConnectionError, asyncio.IncompleteReadError,
                         OSError, ProtocolError):

@@ -6,12 +6,14 @@ exercise genuine serialization boundaries, not shared references, so they
 use wall-clock time with generous deadlines and tiny simulated workloads.
 """
 import asyncio
+import threading
+import time
 
 import pytest
 
 from repro.core.distributor import (AdaptiveSizer, AsyncDistributor,
-                                    ClientProfile, Fetched, HttpServerBase,
-                                    TaskDef)
+                                    ClientProfile, Fetched, FixedSizer,
+                                    HttpServerBase, TaskDef)
 from repro.core.federation import FederatedDistributor
 from repro.core.tickets import LeaseBatch, Ticket
 from repro.core.transport import (PROTOCOL_VERSION, ProtocolError,
@@ -19,6 +21,7 @@ from repro.core.transport import (PROTOCOL_VERSION, ProtocolError,
                                   decode_payload, encode_frame,
                                   encode_payload, read_frame,
                                   spawn_remote_clients)
+from repro.obs import Tracer, trace
 
 
 # module-level so they pickle across the wire
@@ -390,9 +393,12 @@ def test_remote_errors_reported_and_work_still_completes():
         addr = await server.start()
         clients, tasks = spawn_remote_clients(
             addr, [ClientProfile(name="r0", speed=500.0)])
-        # the boom ticket can never complete; wait for the sq tickets only
+        # the boom ticket can never complete; wait for the sq tickets,
+        # for its first error report and for the client's reload after it
         deadline = asyncio.get_event_loop().time() + 30.0
-        while d.queue.results_for(sq_tids) is None:
+        while (d.queue.results_for(sq_tids) is None
+               or not d.queue._tickets[boom_tid].error_reports
+               or clients[0].reloads < 1):
             assert asyncio.get_event_loop().time() < deadline, d.console()
             await asyncio.sleep(0.02)
         reports = []
@@ -639,3 +645,167 @@ def test_reregister_storm_over_wire_zero_stale_serves():
     assert sum(c.revalidations for c in clients) > 0
     # and the origin's push invalidations reached the remote caches
     assert sum(c.push_invalidations for c in clients) > 0
+
+
+# ---------------------------------------------------------------------------
+# Execution off the event loop
+# ---------------------------------------------------------------------------
+
+BLOCK_S = 0.4
+# (arg, thread id, start, end) of each _block_and_log call, and the
+# event loop's thread id and the clients' tracer, as each test sets them;
+# the task reaches them by module, as it is pickled by reference
+_EXEC_LOG: list = []
+_LOOP: dict = {}
+
+
+def _block_and_log(x, static):
+    t0 = time.monotonic()
+    time.sleep(BLOCK_S)
+    _EXEC_LOG.append((x, threading.get_ident(), t0, time.monotonic()))
+    return x
+
+
+def _traced_probe(x, static):
+    with trace.span("probe.block", cat="test", args={"x": x}):
+        pass
+    return {"current": trace.current() is _LOOP["tracer"],
+            "off_loop": threading.get_ident() != _LOOP["thread"]}
+
+
+def _raise_in_thread(x, static):
+    if threading.get_ident() == _LOOP["thread"]:
+        return "ran on the loop"
+    raise RuntimeError("boom in the worker thread")
+
+
+async def _leases_on_parked_clients(task, args, n_clients, *, lease_size=1,
+                                    tracer=None):
+    """Run ``args`` as tickets of ``task`` on ``n_clients`` remote
+    clients that are already parked on a lease request, each lease
+    ``lease_size`` tickets.  Returns the results."""
+    _LOOP["thread"] = threading.get_ident()
+    d = _dist(keep_alive=True, redistribute_min=10.0,
+              sizer=FixedSizer(lease_size))
+    d.register_task(TaskDef("t", task))
+    server = TransportServer(d)
+    addr = await server.start()
+    clients, tasks = spawn_remote_clients(
+        addr, [ClientProfile(name=f"r{i}", speed=0.0)
+               for i in range(n_clients)], tracer=tracer)
+    try:
+        deadline = time.monotonic() + 30.0
+        while (server.stats()["by_type"]["frames_in"]
+               .get("lease_request", 0) < n_clients):
+            assert time.monotonic() < deadline
+            await asyncio.sleep(0.005)
+        tids = d.add_work("t", args)
+        while True:
+            wake = d._wake_event()
+            out = d.queue.results_for(tids)
+            if out is not None:
+                break
+            assert time.monotonic() < deadline, d.console()
+            await d._wait_on(wake, 0.05)
+    finally:
+        for c in clients:
+            await c.stop()
+        await asyncio.gather(*tasks, return_exceptions=True)
+        await d.shutdown()
+        await server.stop()
+    return out
+
+
+def test_tickets_of_two_clients_take_turns_on_one_worker_thread():
+    _EXEC_LOG.clear()
+    out = asyncio.run(_leases_on_parked_clients(_block_and_log, [0, 1], 2))
+    assert out == [0, 1]
+    (_, th0, _, e0), (_, th1, s1, _) = sorted(_EXEC_LOG, key=lambda r: r[2])
+    # the process's one ticket worker ran both, not the loop's thread,
+    # and the second began after the first ended
+    assert th0 == th1 != _LOOP["thread"]
+    assert e0 <= s1
+
+
+def test_event_loop_keeps_ticking_while_a_task_blocks():
+    ticks = []
+
+    async def go():
+        async def ticker():
+            while True:
+                ticks.append(time.monotonic())
+                await asyncio.sleep(0.005)
+        tick = asyncio.get_running_loop().create_task(ticker())
+        try:
+            return await _leases_on_parked_clients(_block_and_log, [7], 1)
+        finally:
+            tick.cancel()
+
+    _EXEC_LOG.clear()
+    assert asyncio.run(go()) == [7]
+    (_, thread, t0, t1), = _EXEC_LOG
+    assert thread != _LOOP["thread"]
+    inside = [t for t in ticks if t0 <= t <= t1]
+    # a task run on the loop would leave one gap of BLOCK_S
+    assert len(inside) >= 5, len(inside)
+    assert max(b - a for a, b in zip(inside, inside[1:])) < BLOCK_S / 2
+
+
+def test_one_client_runs_its_lease_one_ticket_at_a_time_in_order():
+    _EXEC_LOG.clear()
+    tr = Tracer()
+    out = asyncio.run(_leases_on_parked_clients(
+        _block_and_log, [5, 3, 4], 1, lease_size=3, tracer=tr))
+    assert out == [5, 3, 4]
+    assert [r[0] for r in _EXEC_LOG] == [5, 3, 4]
+    assert all(a[3] <= b[2] for a, b in zip(_EXEC_LOG, _EXEC_LOG[1:]))
+    assert len({r[1] for r in _EXEC_LOG}) == 1
+    assert _EXEC_LOG[0][1] != _LOOP["thread"]
+    (span,) = [e for e in tr.events() if e["name"] == "client.execute"]
+    assert span["args"]["tickets"] == 3 and span["args"]["executed"] == 3
+
+
+def test_task_raising_in_the_worker_thread_is_reported_and_reloads():
+    async def go():
+        _LOOP["thread"] = threading.get_ident()
+        d = _dist(grace=2.0)
+        d.register_task(TaskDef("sq", _square))
+        d.register_task(TaskDef("boom", _raise_in_thread))
+        sq_tids = d.add_work("sq", list(range(6)))
+        boom_tid = d.add_work("boom", [0])[0]
+        server = TransportServer(d)
+        addr = await server.start()
+        clients, tasks = spawn_remote_clients(
+            addr, [ClientProfile(name="r0", speed=500.0)])
+        deadline = time.monotonic() + 30.0
+        while (d.queue.results_for(sq_tids) is None
+               or not d.queue._tickets[boom_tid].error_reports
+               or clients[0].reloads < 1):
+            assert time.monotonic() < deadline, d.console()
+            await asyncio.sleep(0.02)
+        reports = list(d.queue._tickets[boom_tid].error_reports)
+        for c in clients:
+            await c.stop()
+        await asyncio.gather(*tasks, return_exceptions=True)
+        await d.shutdown()
+        await server.stop()
+        return d.queue.results_for(sq_tids), reports, clients[0]
+
+    res, reports, client = asyncio.run(go())
+    assert res == [i * i for i in range(6)]
+    # the traceback names the task's frame in the worker thread
+    assert "boom in the worker thread" in reports[0][1]
+    assert "_raise_in_thread" in reports[0][1]
+    assert client.errors >= 1 and client.reloads >= 1
+
+
+def test_task_on_the_worker_thread_records_on_the_clients_tracer():
+    tr = Tracer()
+    _LOOP["tracer"] = tr
+    out = asyncio.run(_leases_on_parked_clients(
+        _traced_probe, [1, 2], 2, tracer=tr))
+    assert out == [{"current": True, "off_loop": True}] * 2
+    probes = [e for e in tr.events() if e["name"] == "probe.block"]
+    assert sorted(e["args"]["x"] for e in probes) == [1, 2]
+    assert all(e.get("block") for e in probes)
+    assert tr.balanced(), tr.open_spans()
