@@ -5,6 +5,11 @@ configuration and a traffic mix.  The harness finds each by its name:
 
 * the configuration at its ``file`` (``bench/configs/<config>.json``),
   with its plain reference ``bench/configs/<reference>.py`` beside it;
+* the program module the configuration names,
+  ``bench/programs/<program>.py``: everything about the model that the
+  harness needs of the program (its config, its gradient task, how many
+  rows a shard takes, the rows the reference follows, the counts, the
+  gradient program's stable name);
 * the traffic mix at ``bench/traffic/<traffic>.json``, read by the one
   generator in ``traffic.py``;
 * the limits of the comparison at ``bench/checks/<workload>.json``;
@@ -22,6 +27,7 @@ from __future__ import annotations
 
 import asyncio
 import gc
+import hashlib
 import importlib.util
 import json
 import statistics
@@ -40,7 +46,6 @@ import traffic as traffic_gen
 
 BENCH = Path(__file__).resolve().parent
 CHECKED_ROUNDS = 3
-TASK_NAME = "cnn_grad_shard"
 # server settings of the paper's deployment, the same in every mix
 WATCHDOG_INTERVAL_S = 0.01
 GRACE_S = 2.0
@@ -54,7 +59,8 @@ class Cell:
     name: str
     chips: int
     config: dict
-    ccfg: Any                # the program's CNNConfig of ``config``
+    program: Any             # the module ``programs/<program>.py``
+    pcfg: Any                # the program's own config of ``config``
     traffic: dict
     limits: dict
     end_to_end: list
@@ -64,11 +70,17 @@ class Cell:
     def reference(self):
         """The configuration's plain reference module."""
         path = self.config_dir / f"{self.config['reference']}.py"
-        return _load_module(f"bench_reference_{self.config['reference']}",
-                            path)
+        return _load_module("bench_reference", path)
 
 
-def _load_module(name: str, path: Path):
+def _load_module(kind: str, path: Path):
+    """The module at ``path``, loaded once.  Its name in ``sys.modules``
+    (which a pickled task's class is found by) holds ``kind``, the
+    file's stem and a digest of its resolved path, so that two
+    directories' files of the same name stay two modules."""
+    path = Path(path).resolve()
+    digest = hashlib.sha1(str(path).encode()).hexdigest()[:12]
+    name = f"{kind}_{path.stem.replace('.', '_')}_{digest}"
     if name in sys.modules:
         return sys.modules[name]
     spec = importlib.util.spec_from_file_location(name, path)
@@ -80,6 +92,15 @@ def _load_module(name: str, path: Path):
     return module
 
 
+def program_module(name: str, bench_dir: Path = BENCH):
+    """The program module ``programs/<name>.py`` under ``bench_dir``."""
+    path = Path(bench_dir) / "programs" / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no program module {name!r}: {path} "
+                                f"does not exist")
+    return _load_module("bench_program", path)
+
+
 def _applies(entry: dict, workload: str) -> bool:
     return "workloads" not in entry or workload in entry["workloads"]
 
@@ -87,8 +108,8 @@ def _applies(entry: dict, workload: str) -> bool:
 def load_cell(spec: dict, workload: str, root: Path,
               bench_dir: Path = BENCH) -> Cell:
     """The cell ``workload`` of the benchmark ``spec``: configuration
-    files resolve against ``root``, traffic mixes and limits under
-    ``bench_dir``."""
+    files resolve against ``root``, program modules, traffic mixes and
+    limits under ``bench_dir``."""
     by_name = {w["name"]: w for w in spec["workloads"]}
     if workload not in by_name:
         raise KeyError(f"no workload {workload!r}; have {sorted(by_name)}")
@@ -96,6 +117,7 @@ def load_cell(spec: dict, workload: str, root: Path,
     cfg_entry = {c["name"]: c for c in spec["configs"]}[w["config"]]
     cfg_path = Path(root) / cfg_entry["file"]
     config = json.loads(cfg_path.read_text())
+    program = program_module(config["program"], bench_dir)
     mix = traffic_gen.load(Path(bench_dir) / "traffic" / f"{w['traffic']}.json")
     limits = json.loads((Path(bench_dir) / "checks" / f"{workload}.json")
                         .read_text())["limits"]
@@ -103,7 +125,8 @@ def load_cell(spec: dict, workload: str, root: Path,
     if missing:
         raise KeyError(f"checks/{workload}.json lacks limits for {missing}")
     return Cell(name=workload, chips=w["chips"], config=config,
-                ccfg=cnn_config(config), traffic=mix, limits=limits,
+                program=program, pcfg=program.program_config(config),
+                traffic=mix, limits=limits,
                 end_to_end=[m for m in spec["end_to_end"]
                             if _applies(m, workload)],
                 per_layer=[m for m in spec["per_layer"]
@@ -207,19 +230,6 @@ def load_peaks(kind: str) -> dict:
     return table["kinds"][kind]
 
 
-def cnn_config(cfg: dict):
-    """The program's ``CNNConfig`` for a configuration file."""
-    from repro.configs.paper_cnn import CNNConfig, ConvSpec
-    return CNNConfig(name=cfg["name"], image_size=cfg["image_size"],
-                     in_channels=cfg["in_channels"],
-                     num_classes=cfg["num_classes"],
-                     convs=tuple(ConvSpec(out_channels=c["out_channels"],
-                                          kernel=c["kernel"], pool=c["pool"])
-                                 for c in cfg["convs"]),
-                     fc_hidden=tuple(cfg["fc_hidden"]),
-                     batch_size=cfg["batch_size"])
-
-
 class _CompileCounter:
     """Counts XLA compilations while armed."""
 
@@ -241,22 +251,21 @@ async def _drive(cell: Cell, seed: int, seconds: float, log: SpanLog,
     from repro.core.federation import FederatedDistributor
     from repro.core.split_parallel import TrainState
     from repro.core.transport import TransportServer, spawn_remote_clients
-    from repro.models.cnn import CnnGradShard
     from repro.optim import adagrad
     from repro.train_fabric import (FederatedTrainer, FederatedTrainingLoop,
                                     FusedServerStep)
 
-    cfg, mix, ccfg = cell.config, cell.traffic, cell.ccfg
-    batch, m = ccfg.batch_size, mix["shards_per_round"]
+    cfg, mix, program = cell.config, cell.traffic, cell.program
+    batch, m = program.rows_per_shard(cfg), mix["shards_per_round"]
     ref = cell.reference()
     opt = adagrad(cfg["optimizer"]["lr"], beta=cfg["optimizer"]["beta"])
     params0 = ref.init_params(cfg, seed)
     params0_host = ref.as_host(params0)
     log_key = f"{cell.name}:{seed}:{id(log)}"
     _SPAN_LOGS[log_key] = log
-    task = TimedGradShard(
-        CnnGradShard(ccfg, n_rows=traffic_gen.dataset_rows(mix, batch),
-                     seed=seed), log_key)
+    n_rows = traffic_gen.dataset_rows(mix, batch)
+    task = TimedGradShard(program.grad_task(cfg, cell.pcfg, n_rows, seed),
+                          log_key)
     fused = FusedServerStep(opt, lr=cfg["optimizer"]["lr"],
                             beta=cfg["optimizer"]["beta"],
                             mode=cfg["server_step"])
@@ -279,7 +288,8 @@ async def _drive(cell: Cell, seed: int, seconds: float, log: SpanLog,
         redistribute_min=mix["redistribute_min_s"],
         watchdog_interval=WATCHDOG_INTERVAL_S, grace=GRACE_S,
         project_name="Bench")
-    fed.register_task(TaskDef(TASK_NAME, task, static_files=("weights",)))
+    fed.register_task(TaskDef(program.TASK_NAME, task,
+                              static_files=("weights",)))
     # a task that raises is reported and redistributed like a crashed
     # browser: count each report, and show the first
     errors: list[tuple[str, str]] = []
@@ -306,7 +316,7 @@ async def _drive(cell: Cell, seed: int, seconds: float, log: SpanLog,
                            prev_features=(), prev_labels=(), prev_mask=(),
                            step=np.zeros((), np.int32))
         trainer = FederatedTrainer(
-            fed, task_name=TASK_NAME, barrier_k=mix["barrier_k"],
+            fed, task_name=program.TASK_NAME, barrier_k=mix["barrier_k"],
             straggler_policy=mix["straggler_policy"],
             timeout=mix["round_timeout_s"])
         loop = FederatedTrainingLoop(
@@ -466,8 +476,8 @@ def reference_readings(cell: Cell, seed: int, params0,
     of each round's rows: the fault of a step that averages over half of
     its batch, planted in the reference."""
     ref = cell.reference()
-    rows = traffic_gen.round_rows(cell.config, cell.traffic, seed,
-                                  CHECKED_ROUNDS)
+    rows = cell.program.round_rows(cell.config, cell.traffic, seed,
+                                   CHECKED_ROUNDS)
     if half:
         rows = [(x[:len(x) // 2], y[:len(y) // 2]) for x, y in rows]
     losses, g1, p3 = ref.train_rounds(cell.config, params0, rows,
@@ -489,8 +499,7 @@ def end_to_end(run_: Run) -> dict[str, float]:
 def read_metric(run_: Run, name: str):
     """The per-layer metric ``name``, from ``metrics/<name>.py``: its
     ``read(run)`` returns a number, or None where it finds nothing."""
-    module = _load_module(f"bench_metric_{name}",
-                          BENCH / "metrics" / f"{name}.py")
+    module = _load_module("bench_metric", BENCH / "metrics" / f"{name}.py")
     return module.read(run_)
 
 

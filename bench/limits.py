@@ -25,6 +25,16 @@ The summary gives, for each number, the largest sound reading, the
 smallest control and fault readings, and the limit the rule gives: a
 third of the way from the lower end to the upper one on a log scale,
 more room above the lower end than below the upper one.
+
+The control and each fault have to fail one of the cell's numbers on
+every seed.  A fault that reads under 10x the lower end sets no upper
+end, and with many rows a round the half-batch fault reads only a few
+times the sound runs: where no number's limit lies under a side's
+smallest reading, each number that can catch it has its limit lowered
+to the log-middle between that reading and the geometric mean of its
+two ends, so that the limit keeps more room above the lower end than
+below the upper one.  A side that no number can catch is listed under
+``uncaught``, and the cell cannot be run as it stands.
 """
 import time
 
@@ -50,7 +60,6 @@ FACTOR = {"control": 3, "half_batch": 10, "state_unchanged": 3}
 def read(workload: str, seeds: list[int]) -> list[dict]:
     """For each seed, every compared number of each side."""
     import harness
-    import traffic
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     cell = harness.load_cell(spec, workload, ROOT)
     harness.use_compile_cache()
@@ -63,8 +72,8 @@ def read(workload: str, seeds: list[int]) -> list[dict]:
                            t_start=time.perf_counter(), say=lambda _: None)
         params0 = ref.as_host(ref.init_params(cell.config, seed))
         reference = harness.reference_readings(cell, seed, params0)
-        rows = traffic.round_rows(cell.config, cell.traffic, seed,
-                                  harness.CHECKED_ROUNDS)
+        rows = cell.program.round_rows(cell.config, cell.traffic, seed,
+                                       harness.CHECKED_ROUNDS)
         unchanged = {
             "losses": ref.train_rounds(frozen, params0, rows)[0],
             "grad": {k: 0 * v for k, v in reference["grad"].items()},
@@ -88,6 +97,21 @@ def limit(lower: float, upper: float) -> float:
     return math.exp(math.log(lower) / 3 + 2 * math.log(upper) / 3)
 
 
+def lower_to_catch(number: dict, side: str) -> bool:
+    """Lower ``number``'s limit under ``side``'s smallest reading where
+    that leaves it above the geometric mean of its two ends; True if
+    it did."""
+    if number["upper"] is None:
+        return False
+    floor = math.sqrt(number["lower"] * number["upper"])
+    end = number["ends"][side]
+    if end <= floor:
+        return False
+    number["limit"] = math.sqrt(floor * end)
+    number["lowered_for"] = side
+    return True
+
+
 def summary(dump: list[dict]) -> dict:
     out = {"seeds": [d["seed"] for d in dump], "numbers": {}}
     for k in compare.NUMBERS:
@@ -102,6 +126,14 @@ def summary(dump: list[dict]) -> dict:
             "lower": lower, "ends": ends, "upper": upper,
             "limit": limit(lower, upper) if upper else None,
             "program": sorted(d["program"][k] for d in dump)}
+    numbers = out["numbers"].values()
+    out["uncaught"] = []
+    for side in FACTOR:
+        if any(n["limit"] is not None and n["ends"][side] > n["limit"]
+               for n in numbers):
+            continue
+        if not [n for n in numbers if lower_to_catch(n, side)]:
+            out["uncaught"].append(side)
     return out
 
 

@@ -19,9 +19,9 @@ def read(run):
         return None
     m = run.cell.traffic["shards_per_round"]
     least = max(
-        flops.server_step_bytes(run.cell.config, m)
+        flops.server_step_bytes(run.cell.program, run.cell.config, m)
         / run.peak("hbm_bytes_per_s"),
-        flops.server_step_flops(run.cell.config, m)
+        flops.server_step_flops(run.cell.program, run.cell.config, m)
         / run.peak("bf16_flops_per_s"))
     step_s = sum(e.seconds for e in execs)
     return 100.0 * len(run.round_walls) * least / step_s

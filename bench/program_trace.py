@@ -350,7 +350,7 @@ def record(cell, *, seed: int, seconds: float, t_start: float,
         # the benchmark span the reduction gave it to
         "programs": {f: [len(pt.programs_named(f)), sorted(
             {str(summary.owners.get(m.name)) for m in pt.programs_named(f)})]
-            for f in ("cnn_loss_and_grads", "member_coeffs",
+            for f in (cell.program.GRAD_PROGRAM, "member_coeffs",
                       "fused_server_step")},
         "device": run.device | {"busy_s": summary.busy_s,
                                 "window_s": summary.window_s},

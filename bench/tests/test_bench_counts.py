@@ -1,6 +1,7 @@
 """The benchmark's yardsticks that need no trace: operation and byte
-counts from shapes against hand counts, the peaks table, the traffic
-generator, and the reference's rows against the program's."""
+counts from shapes against hand counts, through the CNN's program module
+(``programs/cnn.py``) and through ``flops.py``, the peaks table, the
+traffic generator, and the reference's rows against the program's."""
 import json
 import sys
 from pathlib import Path
@@ -15,6 +16,8 @@ import flops  # noqa: E402
 import harness  # noqa: E402
 import traffic  # noqa: E402
 
+cnn = harness.program_module("cnn")
+
 
 def _config(name):
     return json.loads((BENCH / "configs" / f"{name}.json").read_text())
@@ -26,21 +29,22 @@ def _config(name):
 ])
 def test_counts_match_the_hand_counts(name, params, fwd_mflop):
     cfg = _config(name)
-    assert flops.param_count(cfg) == params == cfg["params"]
-    assert flops.forward_flops_per_sample(cfg) / 1e6 == pytest.approx(
+    assert cfg["program"] == "cnn"
+    assert cnn.param_count(cfg) == params == cfg["params"]
+    assert cnn.forward_flops_per_sample(cfg) / 1e6 == pytest.approx(
         fwd_mflop, abs=0.005)
     # backward: a weight gradient per layer, an input gradient per layer
     # but the first, each as many operations as the layer's forward
-    first = flops.layers(cfg)[0]["macs"] * 2
-    fwd = flops.forward_flops_per_sample(cfg)
-    assert flops.train_flops_per_sample(cfg) == 3 * fwd - first
+    first = cnn.layers(cfg)[0]["macs"] * 2
+    fwd = cnn.forward_flops_per_sample(cfg)
+    assert cnn.train_flops_per_sample(cfg) == 3 * fwd - first
     m = 16
-    assert flops.server_step_bytes(cfg, m) == (m + 4) * params * 4
-    assert flops.server_step_flops(cfg, m) == (2 * m + 6) * params
+    assert flops.server_step_bytes(cnn, cfg, m) == (m + 4) * params * 4
+    assert flops.server_step_flops(cnn, cfg, m) == (2 * m + 6) * params
 
 
 def test_fig4_conv_shapes_by_hand():
-    ls = flops.layers(_config("paper_cnn_fig4"))
+    ls = cnn.layers(_config("paper_cnn_fig4"))
     assert [l["macs"] for l in ls] == [
         32 * 32 * 5 * 5 * 3 * 32, 16 * 16 * 5 * 5 * 32 * 32,
         8 * 8 * 5 * 5 * 32 * 64, 1024 * 512, 512 * 10]
@@ -69,7 +73,7 @@ def test_rounds_take_distinct_rows_then_repeat():
 def test_the_reference_sees_the_programs_rows():
     from repro.data import clustered_images
     seed = 2**31 + 9
-    ours = traffic.clustered_images(64, image_size=8, seed=seed)
+    ours = cnn.clustered_images(64, image_size=8, seed=seed)
     theirs = clustered_images(64, image_size=8, seed=seed)
     for a, b in zip(ours, theirs):
         np.testing.assert_array_equal(a, b)
