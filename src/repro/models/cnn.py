@@ -16,11 +16,13 @@ toy regression (see ``benchmarks/federated_training.py``).
 from __future__ import annotations
 
 import functools
+import itertools
 import math
 from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.paper_cnn import CNNConfig
 from repro.obs import trace
@@ -96,6 +98,13 @@ def error_rate(logits, labels):
 # ---------------------------------------------------------------------------
 
 
+def _cnn_loss_and_grads(ccfg, params, images, labels):
+    def loss_fn(p):
+        return nll_loss(forward(p, ccfg, images), labels)
+    with jax.named_scope("cnn_loss_and_grads"):
+        return jax.value_and_grad(loss_fn)(params)
+
+
 @functools.lru_cache(maxsize=None)
 def loss_and_grads(ccfg: CNNConfig):
     """Jitted ``(params, images, labels) -> (mean NLL, grad pytree)`` for
@@ -105,10 +114,37 @@ def loss_and_grads(ccfg: CNNConfig):
 
     @jax.jit
     def cnn_loss_and_grads(params, images, labels):
-        def loss_fn(p):
-            return nll_loss(forward(p, ccfg, images), labels)
-        with jax.named_scope("cnn_loss_and_grads"):
-            return jax.value_and_grad(loss_fn)(params)
+        return _cnn_loss_and_grads(ccfg, params, images, labels)
+
+    return cnn_loss_and_grads
+
+
+@functools.lru_cache(maxsize=None)
+def packed_loss_and_grads(ccfg: CNNConfig, treedef, shapes: tuple,
+                          images_shape: tuple, labels_shape: tuple):
+    """:func:`loss_and_grads` on one packed operand: a flat ``uint32``
+    buffer of the float32 param leaves (``treedef``'s leaf order, each of
+    its ``shapes``), then the images, then the int32 labels, each as its
+    own bits.  The program bitcasts and reshapes them on the device and
+    returns the gradient leaves, flattened in the same order, and the
+    loss last, as one float32 vector.  Cached per config and layout; the
+    program has :func:`loss_and_grads`'s name."""
+    shapes = (*shapes, images_shape, labels_shape)
+    dtypes = [jnp.float32] * (len(shapes) - 1) + [jnp.int32]
+    ends = list(itertools.accumulate(math.prod(s) for s in shapes))
+
+    @jax.jit
+    def cnn_loss_and_grads(words):
+        *leaves, images, labels = [
+            jax.lax.bitcast_convert_type(w, dtype).reshape(shape)
+            for w, dtype, shape in zip(jnp.split(words, ends[:-1]), dtypes,
+                                       shapes)]
+        loss, grads = _cnn_loss_and_grads(
+            ccfg, jax.tree_util.tree_unflatten(treedef, leaves), images,
+            labels)
+        return jnp.concatenate([g.ravel() for g in
+                                jax.tree_util.tree_leaves(grads)]
+                               + [loss[None]])
 
     return cnn_loss_and_grads
 
@@ -131,13 +167,22 @@ class CnnGradShard:
     ``args`` is a ``(lo, hi)`` row slice of :func:`shard_dataset`;
     ``static[weights_key]`` is the round's versioned weight publish
     ``{"round": t, "params": ...}``.  Returns the training-loop contract
-    ``{"grad", "loss", "round"}`` with gradients device_get'ed to plain
-    numpy so the result pickles over the v2 wire protocol.
+    ``{"grad", "loss", "round"}`` with gradients as plain numpy so the
+    result pickles over the v2 wire protocol.
+
+    Served params that are host float32 arrays (a remote client's,
+    decoded from the wire) go to the device in one packed buffer with
+    the rows (:func:`packed_loss_and_grads`), and the gradients come
+    back in one buffer, split on the host into views with the leaves'
+    shapes.  Params already on the device (an in-process client's) stay
+    there: the task calls :func:`loss_and_grads` on them and the rows,
+    and reads each gradient leaf back on its own.
 
     Where a tracer is current (``repro.obs.trace.use``), the copy of the
     params and rows to the device, the program until its gradients are
     ready, and their copy back run one after another, each in its own
-    span (``grad.h2d``, ``grad.compute``, ``grad.d2h``); without one the
+    span (``grad.h2d``, ``grad.compute``, ``grad.d2h``; the copies carry
+    their ``bytes`` and the number of ``arrays`` moved); without one the
     call runs as a single dispatch.
 
     A frozen dataclass of hashable config rather than a closure: remote
@@ -153,17 +198,22 @@ class CnnGradShard:
     def __call__(self, args, static):
         lo, hi = args
         images, labels = shard_dataset(self.ccfg, self.n_rows, self.seed)
+        images, labels = images[lo:hi], labels[lo:hi]
         served = static[self.weights_key]
+        params = served["params"]
         tr = trace.current()
-        if tr is None:
+        leaves, treedef = jax.tree_util.tree_flatten(params)
+        if all(isinstance(x, np.ndarray) and x.dtype == np.float32
+               for x in leaves):
+            loss, grads = _packed_loss_and_grads(tr, self.ccfg, leaves,
+                                                 treedef, images, labels)
+        elif tr is None:
             loss, grads = loss_and_grads(self.ccfg)(
-                served["params"], jnp.asarray(images[lo:hi]),
-                jnp.asarray(labels[lo:hi]))
+                params, jnp.asarray(images), jnp.asarray(labels))
             grads = jax.device_get(grads)
         else:
-            loss, grads = _traced_loss_and_grads(
-                tr, self.ccfg, served["params"], images[lo:hi],
-                labels[lo:hi])
+            loss, grads = _traced_loss_and_grads(tr, self.ccfg, params,
+                                                 images, labels)
         return {"grad": grads, "loss": float(loss),
                 "round": served.get("round", -1)}
 
@@ -172,11 +222,49 @@ def _nbytes(tree) -> int:
     return sum(x.nbytes for x in jax.tree_util.tree_leaves(tree))
 
 
+def _pack(*arrays) -> np.ndarray:
+    """One ``uint32`` buffer of the 32-bit ``arrays``' bits, in order."""
+    return np.concatenate([np.ravel(x).view(np.uint32) for x in arrays])
+
+
+def _packed_loss_and_grads(tr, ccfg, leaves, treedef, images, labels):
+    """The task on host leaves: one buffer to the device, one back.
+    With a tracer ``tr``, each of the three steps in its span."""
+    program = packed_loss_and_grads(
+        ccfg, treedef, tuple(x.shape for x in leaves), images.shape,
+        labels.shape)
+    if tr is None:
+        words = jax.device_put(_pack(*leaves, images, labels))
+        return _unpack(jax.device_get(program(words)), leaves, treedef)
+    with tr.span("grad.h2d", cat="grad") as args:
+        words = _pack(*leaves, images, labels)
+        args["bytes"], args["arrays"] = words.nbytes, 1
+        words = jax.block_until_ready(jax.device_put(words))
+    with tr.span("grad.compute", cat="grad"):
+        out = jax.block_until_ready(program(words))
+    with tr.span("grad.d2h", cat="grad") as args:
+        loss, grads = _unpack(jax.device_get(out), leaves, treedef)
+        args["bytes"], args["arrays"] = _nbytes(grads), 1
+    return loss, grads
+
+
+def _unpack(flat, leaves, treedef):
+    """``(loss, grad tree)`` of the packed program's output on the host:
+    views of ``flat`` with the shapes of ``leaves``."""
+    *grads, loss = np.split(flat, list(itertools.accumulate(
+        x.size for x in leaves)))
+    return loss[0], jax.tree_util.tree_unflatten(
+        treedef, [g.reshape(x.shape) for g, x in zip(grads, leaves)])
+
+
 def _traced_loss_and_grads(tr, ccfg, params, images, labels):
     """:func:`loss_and_grads` with its transfers split from its compute,
     each timed in a span of ``tr``; gradients returned on the host."""
     with tr.span("grad.h2d", cat="grad") as args:
         args["bytes"] = _nbytes((params, images, labels))
+        args["arrays"] = sum(not isinstance(x, jax.Array) for x in
+                             jax.tree_util.tree_leaves(
+                                 (params, images, labels)))
         operands = jax.block_until_ready(
             jax.device_put((params, images, labels)))
     with tr.span("grad.compute", cat="grad"):
@@ -185,4 +273,5 @@ def _traced_loss_and_grads(tr, ccfg, params, images, labels):
     with tr.span("grad.d2h", cat="grad") as args:
         grads = jax.device_get(grads)
         args["bytes"] = _nbytes(grads)
+        args["arrays"] = len(jax.tree_util.tree_leaves(grads))
     return loss, grads

@@ -703,6 +703,9 @@ def test_traced_cnn_round_records_each_host_span_once_per_piece_of_work():
     x_bytes = rows * ccfg.image_size ** 2 * ccfg.in_channels * 4 + rows * 4
     assert all(a["bytes"] == p_bytes + x_bytes for a in by("grad.h2d"))
     assert all(a["bytes"] == p_bytes for a in by("grad.d2h"))
+    # a remote client's task moves its operands in one packed buffer
+    # and its gradients back in one
+    assert all(a["arrays"] == 1 for a in by("grad.h2d") + by("grad.d2h"))
     assert all(a["bytes"] == m * p_bytes for a in by("server_step.h2d"))
     assert all(a["M"] == m for a in by("server_step.coeffs"))
     # the publish is the round tag and every param leaf, all changed

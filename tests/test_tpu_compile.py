@@ -10,6 +10,7 @@ one process may load the TPU library, and every pytest worker imports
 this file.  Keep these tests in this one file for the same reason.
 """
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -92,5 +93,18 @@ def test_cnn_loss_and_grads_compiles_at_fig4_batch(one_chip):
     labels = jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip)
     compiled = cnn.loss_and_grads(FIG4_CNN).lower(
         _fig4_params(one_chip), images, labels).compile()
+    assert compiled.memory_analysis() is not None
+    assert compiled.as_text().startswith("HloModule jit_cnn_loss_and_grads,")
+
+
+def test_packed_cnn_loss_and_grads_compiles_at_fig4_batch(one_chip):
+    b, s, c = FIG4_CNN.batch_size, FIG4_CNN.image_size, FIG4_CNN.in_channels
+    leaves, treedef = jax.tree_util.tree_flatten(_fig4_params(one_chip))
+    shapes = tuple(x.shape for x in leaves)
+    words = sum(math.prod(x) for x in shapes) + b * s * s * c + b
+    program = cnn.packed_loss_and_grads(FIG4_CNN, treedef, shapes,
+                                        (b, s, s, c), (b,))
+    compiled = program.lower(jax.ShapeDtypeStruct(
+        (words,), jnp.uint32, sharding=one_chip)).compile()
     assert compiled.memory_analysis() is not None
     assert compiled.as_text().startswith("HloModule jit_cnn_loss_and_grads,")
